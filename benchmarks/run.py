@@ -550,9 +550,11 @@ def latbench(full=False, n_volumes=None, kind="mixed", schemes=None,
 
 
 def kernels(full=False):
-    """Pallas kernel interpret-mode validation timings."""
+    """Pallas kernel timings: interpreted on a CPU backend, compiled on a
+    TPU (the row's derived column says which)."""
     import jax.numpy as jnp
-    from repro.kernels import ops
+    from repro.kernels import interpret_mode, ops
+    mode = "interpret-mode" if interpret_mode() else "compiled"
     rng = np.random.default_rng(0)
     S = 1 << 14
     n = jnp.asarray(rng.integers(1, 129, S), jnp.int32)
@@ -562,7 +564,7 @@ def kernels(full=False):
     t = jnp.int32(20000)
     ops.segment_select(n, nv, st, state, t)  # compile
     us, _ = _timed(lambda: ops.segment_select(n, nv, st, state, t)[0].block_until_ready())
-    _row("kernels/segsel_16k", us, "interpret-mode")
+    _row("kernels/segsel_16k", us, mode)
     B, Hq, Hkv, D, S2 = 2, 8, 2, 128, 1024
     q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((B, S2, Hkv, D)), jnp.float32)
@@ -570,7 +572,7 @@ def kernels(full=False):
     kl = jnp.full((B,), S2, jnp.int32)
     ops.flash_decode(q, k, v, kl)
     us, _ = _timed(lambda: ops.flash_decode(q, k, v, kl).block_until_ready())
-    _row("kernels/flash_decode_1k", us, "interpret-mode")
+    _row("kernels/flash_decode_1k", us, mode)
 
 
 def roofline(full=False):
@@ -667,6 +669,8 @@ def main() -> None:
                     help="sweep mode: write the per-cell artifact "
                          "(scheme/selector/gp, WA mean ± CI) to this path")
     args, _ = ap.parse_known_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     benches = dict(BENCHES)  # bind fleet flags once, wherever it's dispatched
     benches["fleet"] = functools.partial(fleet, n_volumes=args.volumes,

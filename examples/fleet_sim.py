@@ -27,6 +27,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.fleetshard import simulate_fleet_sweep
 from repro.core.jaxsim import (GCSCHED_NAMES, SCHEME_NAMES, JaxSimConfig,
                                pad_fleet, simulate_fleet)
@@ -98,7 +99,7 @@ def main():
                     help="GC scheduling policy (tick engine; fleet-wide)")
     ap.add_argument("--use-kernels", action="store_true",
                     help="route victim selection + classification through the "
-                         "Pallas kernels (interpret mode on CPU)")
+                         "Pallas kernels")
     ap.add_argument("--sweep", action="store_true",
                     help="heterogeneous policy-grid sweep (one program, every "
                          "volume its own scheme/selector/gp)")
@@ -113,6 +114,7 @@ def main():
                     help="sweep: one program for the whole fleet instead of "
                          "per-scheme groups with pruned dispatch")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.sweep:
         run_sweep(args)

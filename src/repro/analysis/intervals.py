@@ -10,10 +10,10 @@ unknown), covering *every element* of an array value. The transfer rules are
 deliberately conservative: any primitive without a rule maps to unbounded,
 and opaque sub-jaxpr bodies (``scan``/``while``/``pallas_call``) are walked
 with an unknown environment — their equations still reach the lint visitor,
-but contribute nothing to bounds. ``pjit`` and ``cond`` are the two
+but contribute nothing to bounds. ``jit`` and ``cond`` are the two
 structured primitives interpreted *precisely*: jnp-level helpers such as
-``jnp.clip`` / ``jnp.where`` / ``%`` lower to pjit-wrapped sub-jaxprs, so
-recursing into pjit with the caller's operand intervals is what makes
+``jnp.clip`` / ``jnp.where`` / ``%`` lower to jit-wrapped sub-jaxprs, so
+recursing into jit with the caller's operand intervals is what makes
 literal clamp bounds visible at all.
 """
 
@@ -175,7 +175,7 @@ class IntervalAnalysis:
         name = eqn.primitive.name
         n_out = len(eqn.outvars)
 
-        if name in ("pjit", "closed_call", "core_call", "custom_jvp_call",
+        if name in ("jit", "closed_call", "core_call", "custom_jvp_call",
                     "custom_vjp_call", "custom_vjp_call_jaxpr"):
             inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
             if inner is not None and hasattr(inner, "jaxpr"):

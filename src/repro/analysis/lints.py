@@ -299,13 +299,13 @@ def lint_donation(rec):
 
 
 def _donated_reuse(jaxpr, label):
-    """Donated pjit operands / pallas_call aliased operands read after the
+    """Donated jit operands / pallas_call aliased operands read after the
     donating equation, at any jaxpr nesting level."""
     findings = []
     for idx, eqn in enumerate(jaxpr.eqns):
         name = eqn.primitive.name
         donated = ()
-        if name == "pjit":
+        if name == "jit":
             donated = eqn.params.get("donated_invars", ())
         elif name == "pallas_call":
             aliases = dict(eqn.params.get("input_output_aliases", ()) or ())

@@ -11,7 +11,7 @@ pass-through survives tracing untouched). So, per state key:
   read of that table).
 
 Sub-jaxprs never capture state invars behind the analysis' back: ``cond``
-branches, ``scan`` bodies and ``pjit`` callees all receive their operands
+branches, ``scan`` bodies and ``jit`` callees all receive their operands
 through the enclosing equation's ``invars``.
 """
 

@@ -17,7 +17,7 @@ three provenance facts about the leading volume axis:
 
 The transfer rules track the axis through reshapes/transposes/broadcasts,
 keep it across *per-volume* reductions (``axes`` not containing the volume
-dim), recurse precisely through ``pjit``/``cond``/``switch``/``shard_map``
+dim), recurse precisely through ``jit``/``cond``/``switch``/``shard_map``
 and run carry fixpoints for ``scan``/``while``. Batched ``gather`` /
 ``scatter`` use the ``operand_batching_dims`` bookkeeping vmap emits: a
 volume may index freely *within its own row*, never across rows. Any
@@ -177,7 +177,7 @@ class ProvenanceAnalysis:
         name = eqn.primitive.name
         n_out = len(eqn.outvars)
 
-        if name in ("pjit", "closed_call", "core_call", "custom_jvp_call",
+        if name in ("jit", "closed_call", "core_call", "custom_jvp_call",
                     "custom_vjp_call", "custom_vjp_call_jaxpr",
                     "remat_call", "checkpoint"):
             inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")

@@ -239,11 +239,10 @@ def fleet_fixture_trace(cfg, fx, n_volumes=4):
     def fn(st):
         return fx.impl(cfg, st)
     if fx.kind == "fleet_shard":
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec
         mesh = Mesh(np.asarray(jax.devices()[:1]), ("fleet",))
-        fn = shard_map(fn, mesh=mesh, in_specs=(PartitionSpec("fleet"),),
-                       out_specs=PartitionSpec("fleet"), check_rep=False)
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(PartitionSpec("fleet"),),
+                           out_specs=PartitionSpec("fleet"), check_vma=False)
     return trace(f"fleet.{fx.name}", fn, (spec,), state_arg=0,
                  state_out="root")
 

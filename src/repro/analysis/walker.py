@@ -2,7 +2,7 @@
 
 Everything downstream of tracing works on ``jax.core`` jaxprs: equations,
 ``Var``/``Literal`` atoms, and the sub-jaxprs that structured primitives
-(``cond`` branches, ``scan``/``while`` bodies, ``pjit``'s inner function,
+(``cond`` branches, ``scan``/``while`` bodies, ``jit``'s inner function,
 ``pallas_call``'s kernel body) carry in their params. This module is the
 one place that knows how to find those sub-jaxprs and how to classify an
 equation's effects, so the lints stay jaxpr-version-agnostic.
@@ -11,11 +11,7 @@ equation's effects, so the lints stay jaxpr-version-agnostic.
 from __future__ import annotations
 
 from jax import core as jax_core
-
-try:                                    # moved across recent jax versions
-    from jax.extend.core import ClosedJaxpr, Literal
-except ImportError:                     # pragma: no cover - older layouts
-    from jax.core import ClosedJaxpr, Literal
+from jax.extend.core import ClosedJaxpr, Literal
 
 
 def is_literal(atom) -> bool:
@@ -25,16 +21,12 @@ def is_literal(atom) -> bool:
 def subjaxprs(eqn):
     """Every sub-jaxpr an equation carries, normalized to raw ``Jaxpr``.
 
-    ``ClosedJaxpr`` params (pjit/cond/scan/...) are paired with their consts;
+    ``ClosedJaxpr`` params (jit/cond/scan/...) are paired with their consts;
     raw ``Jaxpr`` params (``pallas_call``) get ``None`` consts — their
     constvars' values are unknown to the analysis.
     Yields ``(jaxpr, consts_or_None)``.
     """
-    jaxprs_in_params = getattr(jax_core, "jaxprs_in_params", None)
-    if jaxprs_in_params is None:        # pragma: no cover - jax.core slimmed
-        from jax._src import core as _src_core
-        jaxprs_in_params = _src_core.jaxprs_in_params
-    for sub in jaxprs_in_params(eqn.params):
+    for sub in jax_core.jaxprs_in_params(eqn.params):
         if isinstance(sub, ClosedJaxpr):
             yield sub.jaxpr, sub.consts
         else:

@@ -39,8 +39,7 @@ import itertools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .jaxsim import (
     GCSCHED_IDS,
@@ -93,15 +92,17 @@ class FleetPolicy:
     def max_classes(self) -> int:
         return int(self.n_classes.max())
 
-    def as_state_arrays(self) -> dict:
-        """The (V,) traced-policy arrays `jaxsim.fleet_body` vmaps over."""
+    def as_state_arrays(self, device=None) -> dict:
+        """The (V,) traced-policy arrays `jaxsim.fleet_body` vmaps over,
+        placed on ``device`` (a device or sharding; None = default)."""
+        put = functools.partial(jnp.asarray, device=device)
         return {
-            "p_scheme": jnp.asarray(self.scheme_id, jnp.int32),
-            "p_selector": jnp.asarray(self.selector_id, jnp.int32),
-            "p_gp": jnp.asarray(self.gp_threshold, jnp.float32),
-            "p_ncw": jnp.asarray(self.nc_window, jnp.int32),
-            "p_classes": jnp.asarray(self.n_classes, jnp.int32),
-            "p_gcsched": jnp.asarray(self.gcsched_id, jnp.int32),
+            "p_scheme": put(self.scheme_id, jnp.int32),
+            "p_selector": put(self.selector_id, jnp.int32),
+            "p_gp": put(self.gp_threshold, jnp.float32),
+            "p_ncw": put(self.nc_window, jnp.int32),
+            "p_classes": put(self.n_classes, jnp.int32),
+            "p_gcsched": put(self.gcsched_id, jnp.int32),
         }
 
     def volume(self, i: int) -> dict:
@@ -214,9 +215,9 @@ def shard_mapped_body(cfg: JaxSimConfig, masked: bool, mesh: Mesh):
     independent, so every input/output leaf shards its leading axis and the
     body runs collective-free on each device's slice of the fleet."""
     body = functools.partial(fleet_body, cfg, masked)
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P("fleet"), P("fleet"), P("fleet")),
-                     out_specs=P("fleet"), check_rep=False)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P("fleet"), P("fleet"), P("fleet")),
+                         out_specs=P("fleet"), check_vma=False)
 
 
 @functools.lru_cache(maxsize=None)
@@ -240,32 +241,27 @@ def _replay_fleet(padded: np.ndarray, cfg_h: JaxSimConfig,
                   policy: FleetPolicy, mesh: Mesh | None) -> dict:
     """One fleet replay (no grouping): shard_map over the mesh when more
     than one device is visible, plain jit otherwise. Returns the final
-    batched state (device)."""
-    V = padded.shape[0]
+    batched state (device), blocked until ready. Sharded replays place
+    every input straight onto the mesh (fleet axis split across devices)
+    and may carry trailing copies of the last volume, which pad the fleet
+    to a multiple of the device count; callers keep the first
+    ``padded.shape[0]`` rows."""
     masked = bool((padded < 0).any())
-    pol_arrays = policy.as_state_arrays()
-    nxts = fleet_annotations(padded, policy.scheme_id)
-    if mesh is not None and mesh.size > 1:
-        d = mesh.size
-        pad_rows = (-V) % d
-        if pad_rows:  # replicate the last volume; dropped after the run
-            padded = np.concatenate([padded, np.repeat(padded[-1:], pad_rows, 0)])
-            if nxts is not None:
-                nxts = np.concatenate([nxts, np.repeat(nxts[-1:], pad_rows, 0)])
-            pol_arrays = {k: jnp.concatenate(
-                [v, jnp.repeat(v[-1:], pad_rows, 0)]) for k, v in pol_arrays.items()}
-        st = _sharded_runner(cfg_h, masked, mesh)(
-            jnp.asarray(padded), coerce_fleet_annotations(nxts, padded.shape),
-            pol_arrays)
-        st = jax.block_until_ready(st)
-        if pad_rows:
-            st = jax.tree_util.tree_map(lambda x: x[:V], st)
-    else:
-        st = jax.block_until_ready(
+    if mesh is None or mesh.size == 1:
+        nxts = fleet_annotations(padded, policy.scheme_id)
+        return jax.block_until_ready(
             _run_fleet(cfg_h, jnp.asarray(padded),
                        coerce_fleet_annotations(nxts, padded.shape), masked,
-                       pol_arrays))
-    return st
+                       policy.as_state_arrays()))
+    V = padded.shape[0]
+    rows = np.minimum(np.arange(-(-V // mesh.size) * mesh.size), V - 1)
+    padded, policy = padded[rows], _policy_rows(policy, rows)
+    nxts = fleet_annotations(padded, policy.scheme_id)
+    fleet = NamedSharding(mesh, P("fleet"))
+    return jax.block_until_ready(_sharded_runner(cfg_h, masked, mesh)(
+        jax.device_put(padded, fleet),
+        coerce_fleet_annotations(nxts, padded.shape, device=fleet),
+        policy.as_state_arrays(device=fleet)))
 
 
 def _policy_rows(policy: FleetPolicy, idx: np.ndarray) -> FleetPolicy:
@@ -315,19 +311,21 @@ def simulate_fleet_hetero(traces, cfg: JaxSimConfig, policy: FleetPolicy, *,
             cfg_h, scheme_group=(name,))
         states.append(_replay_fleet(padded[idx], cfg_g,
                                     _policy_rows(policy, idx), mesh))
-    if len(states) == 1:
-        st = states[0]
-    else:  # reassemble volumes in input order (groups share one pytree
-        #    structure: init_state carries every scheme's slice regardless)
-        order = np.argsort(np.concatenate([idx for _, idx in groups]))
-        st = jax.tree_util.tree_map(
-            lambda *xs: jnp.concatenate(xs, axis=0)[order], *states)
+    # reassemble volumes in input order on the host, where the summary reads
+    # them anyway (groups share one pytree structure: init_state carries
+    # every scheme's slice regardless); sharded pad rows are dropped here
+    order = np.argsort(np.concatenate([idx for _, idx in groups]))
+    sizes = [len(idx) for _, idx in groups]
+    st = jax.tree_util.tree_map(
+        lambda *xs: np.concatenate(
+            [x[:n] for x, n in zip(xs, sizes)], axis=0)[order],
+        *jax.device_get(states))
 
     res = summarize_fleet(cfg_h, st, V)
     res["fleet"]["n_devices"] = 1 if mesh is None else mesh.size
     res["fleet"]["n_scheme_groups"] = len(groups)
     if return_state:
-        return res, jax.device_get(st)
+        return res, st
     return res
 
 

@@ -131,7 +131,8 @@ class JaxSimConfig:
     max_gc_per_step: int = 64
     n_segments: int | None = None           # S_max; default sized from capacity
     use_kernels: bool = False               # route hot paths via Pallas kernels
-    kernels_interpret: bool = True          # interpret mode (CPU); False on TPU
+    #                                         (interpreted on CPU, compiled on
+    #                                         TPU: repro.kernels.interpret_mode)
     class_slots: int | None = None          # pad the class axis (hetero fleets)
     sfs_resample: int = 4096                # SFS quantile refresh period
     #                                         (= numpy SFS resample_every)
@@ -430,8 +431,7 @@ def _select_victim(cfg: JaxSimConfig, st):
         from repro.kernels.segsel import segment_select
         idx, _ = segment_select(
             st["seg_n"], st["seg_nvalid"], st["seg_stime"], st["seg_state"],
-            st["t"], selector_id=st["p_selector"],
-            interpret=cfg.kernels_interpret)
+            st["t"], selector_id=st["p_selector"])
         return idx.astype(jnp.int32)
     scores = _scores(st)
     idx = jnp.argmax(scores).astype(jnp.int32)
@@ -443,8 +443,7 @@ def _classify_kernel_call(cfg: JaxSimConfig, st, v, g, from_c1, is_gc):
     _, gids = _dispatch_table(cfg)
     sids = None if cfg.scheme_group is None else gids
     return classify(v, g, from_c1, is_gc, st["ell"],
-                    scheme_id=st["p_scheme"], scheme_ids=sids,
-                    interpret=cfg.kernels_interpret)
+                    scheme_id=st["p_scheme"], scheme_ids=sids)
 
 
 def _select_victims_fleet(cfg: JaxSimConfig, st):
@@ -455,8 +454,7 @@ def _select_victims_fleet(cfg: JaxSimConfig, st):
         from repro.kernels.segsel import segment_select_batch
         idx, _ = segment_select_batch(
             st["seg_n"], st["seg_nvalid"], st["seg_stime"], st["seg_state"],
-            st["t"], selector_ids=st["p_selector"],
-            interpret=cfg.kernels_interpret)
+            st["t"], selector_ids=st["p_selector"])
         return idx.astype(jnp.int32)
     return jax.vmap(functools.partial(_select_victim, cfg))(st)
 
@@ -468,6 +466,12 @@ def _alloc_free_ids(cfg: JaxSimConfig, st, count):
     is exhausted the fill is the sacrificial ``cfg.pad_row`` (never free:
     state 3), not -1 — a -1 scatter index would wrap to the last real row."""
     free = st["seg_state"] == 0
+    if count == 1:
+        # every user write asks for one row: the first free one is an
+        # argmax, one reduction, where nonzero's prefix sum costs several
+        # device ops per scan step (the same row either way)
+        first = jnp.argmax(free).astype(jnp.int32)
+        return jnp.where(free[first], first, cfg.pad_row)[None]
     ids, = jnp.nonzero(free, size=count, fill_value=cfg.pad_row)
     return ids.astype(jnp.int32)
 
@@ -994,11 +998,12 @@ def fleet_annotations(padded: np.ndarray, scheme_ids) -> np.ndarray | None:
     return out
 
 
-def coerce_fleet_annotations(nxts, shape) -> jnp.ndarray:
-    """Device array for the scan's annotation stream; NOBIT fill for None."""
+def coerce_fleet_annotations(nxts, shape, device=None) -> jnp.ndarray:
+    """Device array for the scan's annotation stream, placed on ``device``
+    (a device or sharding; None = default); NOBIT fill for None."""
     if nxts is None:
-        return jnp.full(shape, NOBIT, jnp.int32)
-    return jnp.asarray(nxts, jnp.int32)
+        return jnp.full(shape, NOBIT, jnp.int32, device=device)
+    return jnp.asarray(nxts, jnp.int32, device=device)
 
 
 @functools.partial(jax.jit, static_argnums=0)

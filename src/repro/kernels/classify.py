@@ -29,6 +29,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.placement.jax_schemes import elementwise_chain
 from repro.core.placement.registry import jax_scheme_id
+from repro.kernels import interpret_mode
 
 LANE = 128
 TILE_ROWS = 8
@@ -49,7 +50,7 @@ def _make_classify_kernel(scheme_ids: tuple[int, ...] | None):
 def classify(v: jax.Array, g: jax.Array, from_c1: jax.Array, is_gc: jax.Array,
              ell: jax.Array, *, scheme_id: jax.Array | None = None,
              scheme_ids: tuple[int, ...] | None = None,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool | None = None) -> jax.Array:
     """Placement class ids for a batch of writes. 1-D equal-length inputs.
     ``scheme_id`` (traced int32 scalar) selects the scheme per call/volume;
     omitted = SepBIT (the historical behavior). Only elementwise-registered
@@ -59,7 +60,8 @@ def classify(v: jax.Array, g: jax.Array, from_c1: jax.Array, is_gc: jax.Array,
     select chain to those schemes — the grouped-dispatch path compiles one
     kernel per scheme group instead of chaining the whole zoo. Ids inside
     the tuple classify identically to the full chain; a runtime
-    ``scheme_id`` outside the tuple yields class 0."""
+    ``scheme_id`` outside the tuple yields class 0. ``interpret`` follows
+    the platform (see `repro.kernels.interpret_mode`)."""
     (B,) = v.shape
     tile = TILE_ROWS * LANE
     Bp = ((B + tile - 1) // tile) * tile
@@ -79,7 +81,7 @@ def classify(v: jax.Array, g: jax.Array, from_c1: jax.Array, is_gc: jax.Array,
         in_specs=[scalar, scalar, spec, spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((Bp // LANE, LANE), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(ell.reshape(1, 1).astype(jnp.float32),
       jnp.asarray(scheme_id, jnp.int32).reshape(1, 1), v2, g2, c12, gc2)
     return out.reshape(-1)[:B]

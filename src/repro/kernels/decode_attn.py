@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -64,7 +66,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("kv_tile", "interpret"))
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, kv_len: jax.Array,
-                 *, kv_tile: int = 256, interpret: bool = True) -> jax.Array:
+                 *, kv_tile: int = 256,
+                 interpret: bool | None = None) -> jax.Array:
     """Single-token GQA decode attention.
 
     q: (B, Hq, D); k, v: (B, S, Hkv, D); kv_len: (B,) valid KV entries.
@@ -104,7 +107,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, kv_len: jax.Array,
             pltpu.VMEM((Gp, 128), jnp.float32),
             pltpu.VMEM((Gp, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(lens, qg.reshape(B * Hkv, Gp, D), kp, vp)
     out = out.reshape(B, Hkv, Gp, D)[:, :, :G]
     return out.reshape(B, Hq, D)

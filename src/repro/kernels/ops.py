@@ -1,8 +1,7 @@
 """Public jit'd entry points for the Pallas kernels.
 
-``interpret`` defaults to True (this container is CPU-only; interpret mode
-executes the kernel bodies in Python for correctness validation). On TPU,
-call with interpret=False — the BlockSpecs are written for v5e VMEM tiling.
+Each takes ``interpret=None``: interpreted on the CPU backend, compiled for
+the TPU's VMEM tiling on a chip (`repro.kernels.interpret_mode`).
 """
 
 from __future__ import annotations

@@ -4,8 +4,13 @@ At fleet scale (the paper's deployment context: cloud block storage with
 thousands of volumes × up to millions of segments) victim selection is a
 large masked argmax over segment metadata every GC tick. The kernel streams
 segment records HBM→VMEM in (8, 128)-aligned tiles, scores each tile on the
-VPU, and carries a running (max, argmax) in the output block across the grid
-(its index map is constant, so the buffer persists between grid steps).
+VPU, and folds it *elementwise* into a running (max, argmax) carried as an
+(8, 128) tile in the output block (its index map is constant over the tile
+axis, so the buffer persists between grid steps). Each lane keeps the best
+score it has seen and the lowest flat index that reached it; one small jnp
+reduction over the carried tile (:func:`finish_argmax`) then yields the
+global argmax. Nothing is stored to memory as a scalar, which Mosaic
+refuses in VMEM.
 
 Scores follow core/gc.py exactly:
   greedy:        (n - n_valid) / max(n, 1)
@@ -13,11 +18,11 @@ Scores follow core/gc.py exactly:
 Ineligible segments (not sealed, or zero garbage) score -inf; ties resolve to
 the lowest index (matching jnp.argmax).
 
-The selector is a *runtime* scalar (a (1, 1) SMEM-style block like ``t``):
-heterogeneous fleets vmap this kernel with a different selector id per
-volume, so the choice cannot be baked into the compiled kernel. Both scores
-are evaluated on the VPU and the id picks one — each branch's values are
-unchanged from the static formulation.
+The clock ``t`` and the selector id are *runtime* scalars read from SMEM:
+heterogeneous fleets run a different selector id per volume, so the choice
+cannot be baked into the compiled kernel. Both scores are evaluated on the
+VPU and the id picks one — each branch's values are unchanged from the
+static formulation.
 """
 
 from __future__ import annotations
@@ -27,12 +32,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 LANE = 128
 TILE_ROWS = 8  # (8, 128) int32/fp32 tile
 
-
 GREEDY, COST_BENEFIT = 0, 1   # selector ids (must match jaxsim.SELECTOR_IDS)
+_NO_INDEX = jnp.iinfo(jnp.int32).max
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)   # whole (small) scalar array
+# the single-volume kernel's scalars are (1, 1): vmapping it prepends a
+# squeezed volume axis, and (1, 1) trailing blocks still meet TPU tiling
+_SMEM_1X1 = pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
 
 
 def _score_tile(n, nv, stime, state, t, selector_id):
@@ -48,38 +61,56 @@ def _score_tile(n, nv, stime, state, t, selector_id):
     return jnp.where(eligible, score, -jnp.inf)
 
 
-def _fold_tile_argmax(score, base, score_ref, idx_ref):
-    """Fold one scored (rows, LANE) tile into the running (max, argmax)
-    carried in the (1, 1) output blocks. The argmax carry is exact int32 —
-    a float32 carry would round flat indices above 2^24 to a neighboring
-    segment — and ties resolve to the lowest index (matching jnp.argmax).
-    Shared by the single-volume and batched kernels so the tie-break
-    contract can't drift between them."""
+def _fold_tile_argmax(tile, score, best_ref, idx_ref):
+    """Fold scored tile number ``tile`` of a (rows, LANE) tiling into the
+    lane-wise running (max, argmax) held in ``best_ref`` / ``idx_ref``.
+    Tiles arrive in increasing index order and a lane takes a new value only
+    on a strictly greater score, so each lane keeps the lowest flat index
+    of its best score. The argmax carry is exact int32 — a float32 carry
+    would round flat indices above 2^24 to a neighboring segment. Shared by
+    the single-volume and batched kernels so the tie-break contract can't
+    drift between them."""
+    @pl.when(tile == 0)
+    def _init():
+        best_ref[...] = jnp.full(best_ref.shape, -jnp.inf, jnp.float32)
+        idx_ref[...] = jnp.full(idx_ref.shape, -1, jnp.int32)
+
+    rows, lanes = score.shape
     r = jax.lax.broadcasted_iota(jnp.int32, score.shape, 0)
     c = jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
-    flat = base + r * LANE + c
+    flat = tile * (rows * lanes) + r * lanes + c
+    best = best_ref[...]
+    take = score > best
+    best_ref[...] = jnp.where(take, score, best)
+    idx_ref[...] = jnp.where(take, flat, idx_ref[...])
 
-    local_max = jnp.max(score)
-    local_arg = jnp.min(jnp.where(score >= local_max, flat, jnp.int32(2 ** 30)))
 
-    best = score_ref[0, 0]
-    take = local_max > best
-    score_ref[0, 0] = jnp.where(take, local_max, best)
-    idx_ref[0, 0] = jnp.where(take, local_arg, idx_ref[0, 0])
+def finish_argmax(best, best_idx):
+    """Reduce the kernels' lane-wise carry (trailing (rows, LANE) axes) to
+    ``(idx, score)``: the best score, and the lowest flat index among the
+    lanes that hold it — jnp.argmax's first-occurrence tie-break. ``idx`` is
+    -1 where no segment is eligible (every score -inf)."""
+    top = jnp.max(best, axis=(-2, -1))
+    hit = best == top[..., None, None]
+    idx = jnp.min(jnp.where(hit, best_idx, _NO_INDEX), axis=(-2, -1))
+    return jnp.where(jnp.isfinite(top), idx, -1), top
 
 
 def _segsel_kernel(t_ref, sel_ref, n_ref, nv_ref, stime_ref, state_ref,
-                   score_ref, idx_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        score_ref[0, 0] = -jnp.inf
-        idx_ref[0, 0] = -1
-
+                   best_ref, idx_ref):
     score = _score_tile(n_ref[...], nv_ref[...], stime_ref[...], state_ref[...],
                         t_ref[0, 0], sel_ref[0, 0])
-    _fold_tile_argmax(score, i * TILE_ROWS * LANE, score_ref, idx_ref)
+    _fold_tile_argmax(pl.program_id(0), score, best_ref, idx_ref)
+
+
+def _padded_tiles(x, lead=()):
+    """(..., S) int32 -> (..., Sp // LANE, LANE), Sp a whole number of
+    (TILE_ROWS, LANE) tiles; padding is zero, which scores -inf (state 0)."""
+    tile = TILE_ROWS * LANE
+    S = x.shape[-1]
+    Sp = -(-S // tile) * tile
+    x = jnp.pad(x.astype(jnp.int32), [(0, 0)] * len(lead) + [(0, Sp - S)])
+    return x.reshape(*lead, Sp // LANE, LANE)
 
 
 @functools.partial(jax.jit, static_argnames=("selector", "interpret"))
@@ -87,105 +118,72 @@ def segment_select(seg_n: jax.Array, seg_nvalid: jax.Array, seg_stime: jax.Array
                    seg_state: jax.Array, t: jax.Array, *,
                    selector: str = "cost_benefit",
                    selector_id: jax.Array | None = None,
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """Victim segment argmax. 1-D int32 inputs of equal length (padded to a
     multiple of 1024 internally; padding scores -inf). Returns (idx, score);
     idx == -1 when no segment is eligible.
 
     ``selector_id`` (traced int32 scalar, 0 = greedy / 1 = cost-benefit)
     overrides the static ``selector`` string — per-volume selection for
-    heterogeneous fleets, where this kernel is vmapped over volumes."""
-    (S,) = seg_n.shape
-    tile = TILE_ROWS * LANE
-    Sp = ((S + tile - 1) // tile) * tile
-    pad = Sp - S
+    heterogeneous fleets, where this kernel is vmapped over volumes.
+    ``interpret`` follows the platform (see `repro.kernels.interpret_mode`)."""
     if selector_id is None:
         selector_id = jnp.int32({"greedy": GREEDY, "cost_benefit": COST_BENEFIT}
                                 [selector])
-
-    def prep(x):
-        x = jnp.pad(x.astype(jnp.int32), (0, pad))
-        return x.reshape(Sp // LANE, LANE)
-
-    n2, nv2, st2, state2 = map(prep, (seg_n, seg_nvalid, seg_stime, seg_state))
-
-    out_score, out_idx = pl.pallas_call(
+    n2, nv2, st2, state2 = map(_padded_tiles,
+                               (seg_n, seg_nvalid, seg_stime, seg_state))
+    spec = pl.BlockSpec((TILE_ROWS, LANE), lambda i: (i, 0))
+    carry = pl.BlockSpec((TILE_ROWS, LANE), lambda i: (0, 0))
+    best, best_idx = pl.pallas_call(
         _segsel_kernel,
-        grid=(Sp // tile,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((TILE_ROWS, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_ROWS, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_ROWS, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_ROWS, LANE), lambda i: (i, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
-        interpret=interpret,
-    )(t.reshape(1, 1).astype(jnp.int32),
+        grid=(n2.shape[0] // TILE_ROWS,),
+        in_specs=[_SMEM_1X1, _SMEM_1X1, spec, spec, spec, spec],
+        out_specs=[carry, carry],
+        out_shape=[jax.ShapeDtypeStruct((TILE_ROWS, LANE), jnp.float32),
+                   jax.ShapeDtypeStruct((TILE_ROWS, LANE), jnp.int32)],
+        interpret=interpret_mode(interpret),
+    )(jnp.asarray(t, jnp.int32).reshape(1, 1),
       jnp.asarray(selector_id, jnp.int32).reshape(1, 1), n2, nv2, st2, state2)
-    score = out_score[0, 0]
-    idx = out_idx[0, 0]
-    return jnp.where(jnp.isfinite(score), idx, -1), score
+    return finish_argmax(best, best_idx)
 
 
 def _segsel_batch_kernel(t_ref, sel_ref, n_ref, nv_ref, stime_ref, state_ref,
-                         score_ref, idx_ref):
-    i = pl.program_id(1)          # tile index within the current volume
-
-    @pl.when(i == 0)              # fresh running (max, argmax) per volume
-    def _init():
-        score_ref[0, 0] = -jnp.inf
-        idx_ref[0, 0] = -1
-
-    score = _score_tile(n_ref[0], nv_ref[0], stime_ref[0], state_ref[0],
-                        t_ref[0, 0], sel_ref[0, 0])
-    _fold_tile_argmax(score, i * TILE_ROWS * LANE, score_ref, idx_ref)
+                         best_ref, idx_ref):
+    v = pl.program_id(0)          # volume; its carry resets at its tile 0
+    score = _score_tile(n_ref[...], nv_ref[...], stime_ref[...], state_ref[...],
+                        t_ref[v], sel_ref[v])
+    _fold_tile_argmax(pl.program_id(1), score, best_ref, idx_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def segment_select_batch(seg_n: jax.Array, seg_nvalid: jax.Array,
                          seg_stime: jax.Array, seg_state: jax.Array,
                          t: jax.Array, *, selector_ids: jax.Array,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """Victim argmax for a whole fleet in one kernel launch: (V, S) int32
     segment metadata, per-volume clocks ``t`` and ``selector_ids`` (both
-    (V,)). The fleet GC tick's entry point — one pallas_call with a
-    (volumes × tiles) grid instead of V separate (vmapped) launches; each
-    volume's running (max, argmax) lives in its row of the output block,
-    reset when its first tile arrives. Returns ((V,) idx, (V,) score);
-    idx == -1 where no segment is eligible. Scores/tie-breaks are identical
-    to :func:`segment_select` and the jnp oracle."""
-    V, S = seg_n.shape
-    tile = TILE_ROWS * LANE
-    Sp = ((S + tile - 1) // tile) * tile
-    pad = Sp - S
-
-    def prep(x):
-        x = jnp.pad(x.astype(jnp.int32), ((0, 0), (0, pad)))
-        return x.reshape(V, Sp // LANE, LANE)
-
-    n2, nv2, st2, state2 = map(prep, (seg_n, seg_nvalid, seg_stime, seg_state))
-    scalar = pl.BlockSpec((1, 1), lambda v, i: (v, 0))
-    spec = pl.BlockSpec((1, TILE_ROWS, LANE), lambda v, i: (v, i, 0))
-
-    out_score, out_idx = pl.pallas_call(
+    (V,), read from SMEM). The fleet GC tick's entry point — one pallas_call
+    with a (volumes × tiles) grid instead of V separate (vmapped) launches;
+    each volume's lane-wise carry is its own (TILE_ROWS, LANE) output block.
+    Returns ((V,) idx, (V,) score); idx == -1 where no segment is eligible.
+    Scores/tie-breaks are identical to :func:`segment_select` and the jnp
+    oracle."""
+    V = seg_n.shape[0]
+    n2, nv2, st2, state2 = (_padded_tiles(x, (V,)) for x in
+                            (seg_n, seg_nvalid, seg_stime, seg_state))
+    spec = pl.BlockSpec((None, TILE_ROWS, LANE), lambda v, i: (v, i, 0))
+    carry = pl.BlockSpec((None, TILE_ROWS, LANE), lambda v, i: (v, 0, 0))
+    best, best_idx = pl.pallas_call(
         _segsel_batch_kernel,
-        grid=(V, Sp // tile),
-        in_specs=[scalar, scalar, spec, spec, spec, spec],
-        out_specs=[pl.BlockSpec((1, 1), lambda v, i: (v, 0)),
-                   pl.BlockSpec((1, 1), lambda v, i: (v, 0))],
-        out_shape=[jax.ShapeDtypeStruct((V, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((V, 1), jnp.int32)],
-        interpret=interpret,
-    )(jnp.asarray(t, jnp.int32).reshape(V, 1),
-      jnp.asarray(selector_ids, jnp.int32).reshape(V, 1), n2, nv2, st2, state2)
-    score = out_score[:, 0]
-    idx = out_idx[:, 0]
-    return jnp.where(jnp.isfinite(score), idx, -1), score
+        grid=(V, n2.shape[1] // TILE_ROWS),
+        in_specs=[_SMEM, _SMEM, spec, spec, spec, spec],
+        out_specs=[carry, carry],
+        out_shape=[jax.ShapeDtypeStruct((V, TILE_ROWS, LANE), jnp.float32),
+                   jax.ShapeDtypeStruct((V, TILE_ROWS, LANE), jnp.int32)],
+        interpret=interpret_mode(interpret),
+    )(jnp.asarray(t, jnp.int32).reshape(V),
+      jnp.asarray(selector_ids, jnp.int32).reshape(V), n2, nv2, st2, state2)
+    return finish_argmax(best, best_idx)
 
 
 def analysis_entries(n_segments: int = 1024, n_volumes: int = 4):

@@ -261,3 +261,33 @@ def test_zipfprob_matches_closed_form():
     got = float(ops.pr_user_bit_kernel(p, 500.0, 2000.0))
     want = pr_user_bit(500, 2000, n=n, alpha=1.0)
     assert got == pytest.approx(want, abs=2e-3)
+
+
+def _pallas_interpret_flags(fn, *args):
+    from repro.analysis.walker import iter_eqns
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    return [e.params["interpret"] for e in iter_eqns(jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
+def test_interpret_mode_follows_the_platform(monkeypatch):
+    """``interpret=None`` interprets on the CPU backend (this suite's) and
+    compiles on a TPU; any other backend is refused. There is no config
+    field that could run the interpreter on a chip."""
+    from repro.core.jaxsim import JaxSimConfig
+    from repro.kernels import interpret_mode
+
+    assert jax.default_backend() == "cpu"
+    z = jnp.zeros(64, jnp.int32)
+    assert _pallas_interpret_flags(
+        lambda a: ops.segment_select(a, a, a, a, jnp.int32(3)), z) == [True]
+    assert _pallas_interpret_flags(
+        lambda a: ops.classify(a, a, a, a, jnp.float32(1.0)), z) == [True]
+    assert interpret_mode(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode(None) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        interpret_mode(None)
+    with pytest.raises(TypeError, match="kernels_interpret"):
+        JaxSimConfig(n_lbas=64, kernels_interpret=True)

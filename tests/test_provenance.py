@@ -43,10 +43,10 @@ def test_join_lattice():
     assert join(NONE, NONE) == NONE
 
 
-# -- elementwise / pjit --------------------------------------------------------
+# -- elementwise / jit ---------------------------------------------------------
 
 def test_elementwise_keeps_axis_through_pjit():
-    """jnp.clip lowers to a pjit sub-jaxpr; the axis must survive the
+    """jnp.clip lowers to a jit sub-jaxpr; the axis must survive the
     recursion and the implicit broadcasts inside."""
     (p,) = _prov(lambda x: jnp.clip(x * 2 + 1, 0, 100), _vec())
     assert p == axis(0)
@@ -225,9 +225,9 @@ def test_sa503_on_donated_pjit_read_after():
         return y + x          # reads x after its buffer was donated
 
     rec = tracing.trace("synthetic.donate", fn, (_vec(),))
-    # the traced pjit eqn must actually carry the donation marker,
+    # the traced jit eqn must actually carry the donation marker,
     # otherwise this test is vacuous
-    assert any(e.primitive.name == "pjit" and any(
+    assert any(e.primitive.name == "jit" and any(
         e.params.get("donated_invars", ()))
         for e in rec.jaxpr.eqns)
     codes = {f.code for f in lints.lint_donation(rec)}

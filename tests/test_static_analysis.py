@@ -126,7 +126,7 @@ def test_drift_lint_catches_spec_mismatch():
 
 
 def test_interval_engine_sees_through_pjit():
-    """jnp.clip lowers to a pjit-wrapped sub-jaxpr; the interval engine
+    """jnp.clip lowers to a jit-wrapped sub-jaxpr; the interval engine
     must recurse into it to see the literal clamp bounds."""
     import jax
     import jax.numpy as jnp
