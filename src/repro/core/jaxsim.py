@@ -119,6 +119,22 @@ GCSCHED_NAMES = tuple(GCSCHED_IDS)
 # p50 == p99 == write_cost exactly.
 LAT_BUCKETS_PER_OCTAVE = 4
 
+# `jax.named_scope` names on the tick engine's fleet path (`fleet_step`,
+# `fleet_gc_tick`). They reach the compiled program only as op metadata, so
+# a profiler trace's device ops can be attributed to them
+# (`bench/scopes.py`); the metric (`bench/metrics/`) that reads each:
+SCOPES = (
+    "user_write",   # user_write_us_per_step: the vmapped user write
+    "gc_tick",      # gc_tick_us_per_step: the whole tick loop, with the four
+    #                 below nested in it as gc_tick/<name>
+    "guard",        # (in gc_tick_us_per_step) the GP guard, in cond and body
+    "select",       # gc_select_us_per_tick: victim selection
+    "rewrite",      # gc_rewrite_us_per_tick: the vmapped _gc_once
+    "merge",        # gc_merge_us_per_tick: the masked select of every leaf;
+    #                 select, rewrite and merge ops run once per tick, which
+    #                 counts ticks for gc_tick_occupancy_pct
+)
+
 
 @dataclasses.dataclass(frozen=True)
 class JaxSimConfig:
@@ -710,11 +726,12 @@ def fleet_gc_tick(cfg: JaxSimConfig, st, step_active=None):
     tick counter enforces the same ``max_gc_per_step`` budget), which is
     what keeps fleet replays bit-identical to single-volume runs."""
     def need(st, stalled):
-        over = jax.vmap(_gp)(st) > st["p_gp"]
-        over = over & ~jax.vmap(functools.partial(_gc_deferred, cfg))(st)
-        over = over & ~stalled
-        if step_active is not None:
-            over = over & step_active
+        with jax.named_scope("guard"):
+            over = jax.vmap(_gp)(st) > st["p_gp"]
+            over = over & ~jax.vmap(functools.partial(_gc_deferred, cfg))(st)
+            over = over & ~stalled
+            if step_active is not None:
+                over = over & step_active
         return over
 
     def cond(carry):
@@ -724,17 +741,22 @@ def fleet_gc_tick(cfg: JaxSimConfig, st, step_active=None):
     def body(carry):
         st, i, stalled = carry
         active = need(st, stalled)
-        victims = _select_victims_fleet(cfg, st)
+        with jax.named_scope("select"):
+            victims = _select_victims_fleet(cfg, st)
         do = active & (victims >= 0)
-        new = jax.vmap(functools.partial(_gc_once, cfg))(st, victims)
-        st = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(do.reshape(do.shape + (1,) * (a.ndim - 1)),
-                                   a, b), new, st)
+        with jax.named_scope("rewrite"):
+            new = jax.vmap(functools.partial(_gc_once, cfg))(st, victims)
+        with jax.named_scope("merge"):
+            st = jax.tree_util.tree_map(
+                lambda a, b: jnp.where(
+                    do.reshape(do.shape + (1,) * (a.ndim - 1)), a, b),
+                new, st)
         return st, i + 1, stalled | (active & (victims < 0))
 
     V = st["t"].shape[0]
-    st, _, _ = jax.lax.while_loop(
-        cond, body, (st, jnp.int32(0), jnp.zeros(V, bool)))
+    with jax.named_scope("gc_tick"):
+        st, _, _ = jax.lax.while_loop(
+            cond, body, (st, jnp.int32(0), jnp.zeros(V, bool)))
     return st
 
 
@@ -1144,7 +1166,8 @@ def fleet_step(cfg: JaxSimConfig, masked: bool, st: dict, lbas: jnp.ndarray,
         return jax.vmap(functools.partial(inner, cfg))(st, lbas, nxs)
 
     write = _masked_write if masked else _user_write
-    st = jax.vmap(functools.partial(write, cfg))(st, lbas, nxs)
+    with jax.named_scope("user_write"):
+        st = jax.vmap(functools.partial(write, cfg))(st, lbas, nxs)
     st = fleet_gc_tick(cfg, st, (lbas >= 0) if masked else None)
     if cfg.timing:
         new = jax.vmap(functools.partial(_charge_gc, cfg))(st)

@@ -120,3 +120,31 @@ def test_fleet_program_fits_one_chip(one_chip):
                       jax.tree_util.tree_leaves(jaxsim.state_spec(cfg)))
     assert mem.output_size_in_bytes >= FLEET_VOLUMES * state_bytes
     assert total < HBM_BYTES
+
+
+def test_fleet_scopes_leave_the_v5e_program_unchanged(one_chip, monkeypatch):
+    """`jaxsim.SCOPES` reach the v5e program only as op metadata: without
+    them it compiles to the same instructions once that is stripped."""
+    import contextlib
+    import functools
+    import re
+    cfg = jaxsim.JaxSimConfig(n_lbas=2 ** 12, segment_size=2 ** 10)
+    trace = _spec(one_chip, (2, 256))
+    pols = {k: _spec(one_chip, v.shape, v.dtype) for k, v in
+            jaxsim.broadcast_policies(cfg, 2).items()}
+
+    def stripped():
+        body = jax.jit(functools.partial(jaxsim.fleet_body, cfg, False))
+        text = body.lower(trace, trace, pols).compile().as_text()
+        assert ("gc_tick/while/body/rewrite" in text) == (
+            jax.named_scope is not null_scope)
+        text = re.sub(r"^(?:FileNames|FunctionNames|FileLocations|"
+                      r"StackFrames)\n(?:.+\n)*?\n", "", text, flags=re.M)
+        return re.sub(r", metadata=\{[^}]*\}", "", text)
+
+    def null_scope(name):
+        return contextlib.nullcontext()
+
+    scoped = stripped()
+    monkeypatch.setattr(jax, "named_scope", null_scope)
+    assert stripped() == scoped
