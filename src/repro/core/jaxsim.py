@@ -521,6 +521,18 @@ def _gc_bookkeeping(cfg: JaxSimConfig, st, victim):
     return st, lba_v, utime_v, classes, free_ids
 
 
+def _class_lookup(tbl, cls):
+    """``tbl[..., cls]`` for classes ``cls`` already clipped to the C class
+    slots on ``tbl``'s last axis: a static C-way select, C elementwise ops
+    that fuse into one loop, where an index would be one element gather per
+    slot (a generic TPU gather, each entry fetched alone)."""
+    out = jnp.broadcast_to(tbl[..., 0], jnp.broadcast_shapes(
+        tbl.shape[:-1], cls.shape))
+    for c in range(1, tbl.shape[-1]):
+        out = jnp.where(cls == c, tbl[..., c], out)
+    return out
+
+
 def _gc_once(cfg: JaxSimConfig, st, victim):
     """Rewrite one victim segment: one fused segmented scatter over
     ``(class, rank)`` keys.
@@ -549,8 +561,8 @@ def _gc_once(cfg: JaxSimConfig, st, victim):
     onehot = (classes[:, None]
               == jnp.arange(C, dtype=jnp.int32)[None, :]).astype(jnp.int32)
     cum = jnp.cumsum(onehot, axis=0)                      # (s, C)
-    rank = jnp.take_along_axis(cum, slot_cls[:, None], 1)[:, 0] - 1
-    k = cum[-1]                                           # (C,) per-class count
+    rank = _class_lookup(cum, slot_cls) - 1
+    k = cum[s - 1]       # (C,) per-class count; a static row, a slice under vmap
 
     # per-class destinations: the class's current open segment, spilling into
     # a fresh free segment once full. Open sids, fresh ids, and the victim
@@ -568,10 +580,14 @@ def _gc_once(cfg: JaxSimConfig, st, victim):
     took2 = k - took1
 
     live = classes >= 0
-    in_first = live & (rank < room[slot_cls])
-    dst_sid = jnp.where(live, jnp.where(in_first, sids[slot_cls],
-                                        free_ids[slot_cls]), drop)
-    dst_off = jnp.where(in_first, n0[slot_cls] + rank, rank - room[slot_cls])
+    slot_room = _class_lookup(room, slot_cls)
+    in_first = live & (rank < slot_room)
+    dst_sid = jnp.where(live, jnp.where(in_first,
+                                        _class_lookup(sids, slot_cls),
+                                        _class_lookup(free_ids, slot_cls)),
+                        drop)
+    dst_off = jnp.where(in_first, _class_lookup(n0, slot_cls) + rank,
+                        rank - slot_room)
     seg_lba = st["seg_lba"].at[dst_sid, dst_off].set(lba_v, mode="drop")
     seg_utime = st["seg_utime"].at[dst_sid, dst_off].set(utime_v, mode="drop")
     seg_valid = st["seg_valid"].at[dst_sid, dst_off].set(True, mode="drop")

@@ -148,3 +148,28 @@ def test_fleet_scopes_leave_the_v5e_program_unchanged(one_chip, monkeypatch):
     scoped = stripped()
     monkeypatch.setattr(jax, "named_scope", null_scope)
     assert stripped() == scoped
+
+
+def test_gc_rewrite_gathers_from_no_class_table(one_chip):
+    """The v5e fleet program's GC rewrite reads each victim slot's class
+    table entries by select: no gather under `gc_tick/.../rewrite` reads an
+    operand whose last dimension is the class axis."""
+    import functools
+    import re
+    cfg = jaxsim.JaxSimConfig(n_lbas=2 ** 12, segment_size=2 ** 10)
+    trace = _spec(one_chip, (2, 256))
+    pols = {k: _spec(one_chip, v.shape, v.dtype) for k, v in
+            jaxsim.broadcast_policies(cfg, 2).items()}
+    body = jax.jit(functools.partial(jaxsim.fleet_body, cfg, False))
+    text = body.lower(trace, trace, pols).compile().as_text()
+    shapes = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.-]+) = \w+\[([\d,]*)\]",
+                             text, flags=re.M))
+    rewrite = []
+    for line in text.splitlines():
+        op = re.search(r"= \S+ gather\((%[\w.-]+)", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if op and name and re.search(r"gc_tick/.*/rewrite/", name.group(1)):
+            rewrite.append(shapes[op.group(1)].split(","))
+    assert rewrite                       # the victim's rows are still read
+    C = cfg.n_class_slots
+    assert not [d for d in rewrite if int(d[-1]) == C]
